@@ -35,6 +35,9 @@ def main() -> None:
                     help="directory for the BENCH_<name>.json files")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable_compile_cache()
+
     from benchmarks import (ablation_cleanbits, ans_throughput,
                             codec_compile, dataset_rate, fig3_chain,
                             hvae_rate, latent_lm_gain, lm_compression,

@@ -218,7 +218,7 @@ _TRANSPARENT = frozenset({"broadcast_in_dim", "reshape", "squeeze",
                           "concatenate", "pad", "select_n",
                           "convert_element_type", "stop_gradient"})
 # Call-like wrappers (jnp.floor/round are jit-wrapped composites).
-_WRAPPERS = frozenset({"pjit", "closed_call", "core_call", "remat2",
+_WRAPPERS = frozenset({"jit", "closed_call", "core_call", "remat2",
                        "checkpoint", "custom_jvp_call",
                        "custom_vjp_call"})
 

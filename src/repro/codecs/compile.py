@@ -160,53 +160,62 @@ def _stack_spec(axis: str) -> ans.ANSStack:
 
 def _mesh_coder_programs(mesh) -> Dict[str, Any]:
     """The shard_map'd twins of the three fused coder entry points."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     (axis,) = mesh.axis_names
     st = _stack_spec(axis)
     lane1 = P(None, axis)          # [steps, lanes]
 
     def push(stack, starts, freqs, *, precision, backend=None):
-        return shard_map(
+        return jax.shard_map(
             lambda s, a, f: ans_ops.push_many(
                 s, a, f, precision=precision, backend=backend),
             mesh=mesh, in_specs=(st, lane1, lane1), out_specs=st,
-            check_rep=False)(stack, starts, freqs)
+            check_vma=False)(stack, starts, freqs)
+
+    # Popped symbols leave as replicated arrays: the eager float model
+    # that consumes them next then runs at single-device shapes on
+    # every device, so its float bits (and the tables built from them)
+    # are the meshless path's. On lane-sharded inputs XLA may
+    # partition the model's matmuls differently, and a decode whose
+    # posterior push sees other bits than the encoder's pop is lossy.
+    replicated = NamedSharding(mesh, P())
 
     def pop_dyn(stack, tables, *, precision, backend=None):
-        return shard_map(
+        stack, syms = jax.shard_map(
             lambda s, t: ans_ops.pop_many_dyn(
                 s, t, precision=precision, backend=backend),
             mesh=mesh, in_specs=(st, P(None, axis, None)),
-            out_specs=(st, lane1), check_rep=False)(stack, tables)
+            out_specs=(st, lane1), check_vma=False)(stack, tables)
+        return stack, jax.lax.with_sharding_constraint(syms, replicated)
 
     def pop_grid(stack, *, mu, sigma, kind, steps, lat_bits, precision,
                  backend=None):
         spec = lane1 if jnp.ndim(mu) == 2 else P()
-        return shard_map(
+        stack, syms = jax.shard_map(
             lambda s, m, g: ans_ops.pop_many_grid(
                 s, kind, m, g, steps, lat_bits, precision=precision,
                 backend=backend),
             mesh=mesh, in_specs=(st, spec, spec),
-            out_specs=(st, lane1), check_rep=False)(stack, mu, sigma)
+            out_specs=(st, lane1), check_vma=False)(stack, mu, sigma)
+        return stack, jax.lax.with_sharding_constraint(syms, replicated)
 
     def push_grid(stack, idxT, mu, sigma, *, kind, bits, precision,
                   backend=None):
         spec = lane1 if jnp.ndim(mu) == 2 else P()
-        return shard_map(
+        return jax.shard_map(
             lambda s, i, m, g: _push_grid_body(
                 s, i, m, g, kind=kind, bits=bits, precision=precision,
                 backend=backend),
             mesh=mesh, in_specs=(st, lane1, spec, spec), out_specs=st,
-            check_rep=False)(stack, idxT, mu, sigma)
+            check_vma=False)(stack, idxT, mu, sigma)
 
     def push_table(stack, tables, symT, *, precision, backend=None):
-        return shard_map(
+        return jax.shard_map(
             lambda s, t, y: _push_table_body(
                 s, t, y, precision=precision, backend=backend),
             mesh=mesh, in_specs=(st, P(None, axis, None), lane1),
-            out_specs=st, check_rep=False)(stack, tables, symT)
+            out_specs=st, check_vma=False)(stack, tables, symT)
 
     return {
         "push": _coder_jits(push, ("precision", "backend")),

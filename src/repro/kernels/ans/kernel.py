@@ -1,15 +1,35 @@
-"""Pallas TPU kernel: lane-vectorized rANS push (the coder's hot loop).
+"""Pallas TPU kernels: the lane-vectorized rANS coder loops.
 
-TPU mapping (DESIGN.md section 3): lanes tile onto the VPU's (8, 128)
-registers; heads live in VMEM across the whole symbol loop; the
-data-dependent "emit" branch of scalar rANS is a masked vector op (the
-uint32/16-bit-renorm design guarantees at most one emission per push, so
-the loop body is branchless). The kernel emits a dense (chunk, need)
-emission list; stack compaction (a cumsum scatter) stays outside in XLA
-where the irregular write pattern is handled well.
+TPU mapping: lanes lie on the 128-wide minor axis of every block. The
+grid is (lane tiles, step blocks): lane tiles are independent
+("parallel"); step blocks run in order ("arbitrary"), with the carried
+coder state (heads, per-lane read counters) resident in the output
+blocks across them. ``_step_block`` sizes the step blocks so that a
+long ``Repeat`` never outgrows VMEM, and ``_compiler_params`` states
+the VMEM the blocks need. Heads travel as ``[1, lanes]`` rows (a 1-D
+block's layout is not one Mosaic accepts above 128 lanes).
 
-Validated bit-exactly against the pure-jnp oracle (ref.py) under
-interpret=True over shape/precision sweeps (tests/test_kernels.py).
+Everything inside a kernel is integer arithmetic:
+
+  * the data-dependent "emit" branch of scalar rANS is a masked vector
+    op (uint32 state, 16-bit renorm: at most one emission per push);
+  * the decode symbol search is compare-and-count over a cumulative
+    starts table, in int32 (Mosaic reduces no unsigned integers):
+    ``sym = #(F <= slot) - 1``, ``start = max F <= slot``,
+    ``next = min F > slot``;
+  * the renormalization read takes lane ``l``'s ``r[l]``-th chunk of a
+    feed pre-gathered in XLA, by compare-and-select over aligned 8-row
+    groups (Mosaic lowers no per-lane vector gather);
+  * the Gaussian and logistic grid pops get their fixed-point CDF as a
+    table built in XLA from the canonical starts expression
+    (``xla._grid_starts_fn``) and run the dynamic-table kernel. Mosaic
+    lowers no ``erf``, and keeping float math out of the kernel keeps
+    its bits those of the XLA twins by construction.
+
+Stack compaction (a cumsum scatter) stays outside in XLA. Validated
+bit-exactly against the pure-jnp oracle (ref.py) under interpret=True
+(tests/test_kernels.py, tests/test_dispatch.py); every kernel is also
+compiled for a described v5e chip by tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -19,8 +39,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE_TILE = 128
+
+# VMEM for one buffer of the step-blocked operands; Pallas double
+# buffers them, and the whole-stream feed comes on top.
+_STEP_BLOCK_BYTES = 4 << 20
+_VMEM_FLOOR_BYTES = 16 << 20
 
 
 def _check_lanes(lanes: int, lane_tile: int = LANE_TILE) -> None:
@@ -32,27 +58,68 @@ def _check_lanes(lanes: int, lane_tile: int = LANE_TILE) -> None:
             f"lane_tile ({lane_tile}); ops.py pads before calling")
 
 
-def _push_kernel(head_ref, starts_ref, freqs_ref,
-                 out_head_ref, chunks_ref, need_ref, *, precision: int):
-    """One lane-tile: sequentially push ``steps`` symbols per lane.
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
-    head_ref: uint32[LANE_TILE]; starts/freqs: uint32[steps, LANE_TILE];
-    chunks/need out: uint32[steps, LANE_TILE].
+
+def _step_block(steps: int, row_bytes: int) -> int:
+    """Steps per grid block: all of them when they fit the budget, else
+    a multiple of 8 (the sublane tile of the ``[steps, lanes]`` blocks).
+    ``row_bytes`` is the VMEM one step occupies across the step-blocked
+    operands."""
+    if steps * row_bytes <= _STEP_BLOCK_BYTES:
+        return steps
+    return max(8, _STEP_BLOCK_BYTES // row_bytes // 8 * 8)
+
+
+def _pad_steps(x: jnp.ndarray, padded: int) -> jnp.ndarray:
+    if x.shape[0] == padded:
+        return x
+    widths = [(0, padded - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+    return jnp.pad(x, widths)
+
+
+def _compiler_params(step_block_bytes: int, resident_bytes: int):
+    need = 2 * (step_block_bytes + resident_bytes) + (4 << 20)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=max(_VMEM_FLOOR_BYTES, need))
+
+
+def _block_len(steps: int, st: int):
+    """Valid steps in the current step block (static when ``st``
+    divides ``steps``; the padded tail block runs short)."""
+    if steps % st == 0:
+        return st
+    return jnp.minimum(st, steps - pl.program_id(1) * st)
+
+
+def _push_kernel(head_ref, starts_ref, freqs_ref,
+                 out_head_ref, chunks_ref, need_ref, *, precision: int,
+                 steps: int, st: int):
+    """One (lane tile, step block): push the block's symbols per lane.
+
+    head_ref/out_head_ref: uint32[1, LANE_TILE]; starts/freqs/chunks/
+    need: uint32[st, LANE_TILE]. ``out_head_ref`` carries the heads
+    across step blocks.
     """
-    steps = starts_ref.shape[0]
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_head_ref[...] = head_ref[...]
 
     def body(t, head):
-        start = starts_ref[t, :]
-        freq = freqs_ref[t, :]
+        start = starts_ref[pl.ds(t, 1), :]
+        freq = freqs_ref[pl.ds(t, 1), :]
         x_max = freq << (32 - precision)
         need = head >= x_max
-        chunk = jnp.where(need, head & 0xFFFF, 0).astype(jnp.uint32)
-        chunks_ref[t, :] = chunk
-        need_ref[t, :] = need.astype(jnp.uint32)
+        chunks_ref[pl.ds(t, 1), :] = jnp.where(
+            need, head & 0xFFFF, 0).astype(jnp.uint32)
+        need_ref[pl.ds(t, 1), :] = need.astype(jnp.uint32)
         head = jnp.where(need, head >> 16, head)
         return ((head // freq) << precision) + (head % freq) + start
 
-    out_head_ref[...] = jax.lax.fori_loop(0, steps, body, head_ref[...])
+    out_head_ref[...] = jax.lax.fori_loop(0, _block_len(steps, st), body,
+                                          out_head_ref[...])
 
 
 def push_emit(head: jnp.ndarray, starts: jnp.ndarray, freqs: jnp.ndarray,
@@ -65,38 +132,38 @@ def push_emit(head: jnp.ndarray, starts: jnp.ndarray, freqs: jnp.ndarray,
     """
     steps, lanes = starts.shape
     _check_lanes(lanes, lane_tile)
-    grid = (lanes // lane_tile,)
-    kernel = functools.partial(_push_kernel, precision=precision)
-    return pl.pallas_call(
+    row_bytes = 4 * lane_tile * 4
+    st = _step_block(steps, row_bytes)
+    padded = _round_up(steps, st)
+    kernel = functools.partial(_push_kernel, precision=precision,
+                               steps=steps, st=st)
+    head_spec = pl.BlockSpec((1, lane_tile), lambda i, j: (0, i))
+    row_spec = pl.BlockSpec((st, lane_tile), lambda i, j: (j, i))
+    new_head, chunks, need = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-        ],
+        grid=(lanes // lane_tile, padded // st),
+        in_specs=[head_spec, row_spec, row_spec],
+        out_specs=[head_spec, row_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((lanes,), jnp.uint32),
-            jax.ShapeDtypeStruct((steps, lanes), jnp.uint32),
-            jax.ShapeDtypeStruct((steps, lanes), jnp.uint32),
+            jax.ShapeDtypeStruct((1, lanes), jnp.uint32),
+            jax.ShapeDtypeStruct((padded, lanes), jnp.uint32),
+            jax.ShapeDtypeStruct((padded, lanes), jnp.uint32),
         ],
+        compiler_params=_compiler_params(st * row_bytes, 0),
         interpret=interpret,
-    )(head, starts, freqs)
+    )(head.reshape(1, lanes), _pad_steps(starts, padded),
+      _pad_steps(freqs, padded))
+    return new_head[0], chunks[:steps], need[:steps]
 
 
 def _peek_kernel(head_ref, slots_out_ref, *, precision: int):
     """Single-step vector peek: the decode slot per lane.
 
     The honest single-step kernel: one masked AND per lane, no loop. The
-    real multi-step decode path is ``_pop_table_kernel`` below.
+    real multi-step decode path is ``_pop_kernel`` below.
     """
     mask = jnp.uint32((1 << precision) - 1)
-    slots_out_ref[0, :] = head_ref[...] & mask
+    slots_out_ref[...] = head_ref[...] & mask
 
 
 def pop_slots(head: jnp.ndarray, precision: int,
@@ -106,54 +173,155 @@ def pop_slots(head: jnp.ndarray, precision: int,
     lanes = head.shape[0]
     _check_lanes(lanes, lane_tile)
     kernel = functools.partial(_peek_kernel, precision=precision)
+    spec = pl.BlockSpec((1, lane_tile), lambda i: (0, i))
     out = pl.pallas_call(
         kernel,
         grid=(lanes // lane_tile,),
-        in_specs=[pl.BlockSpec((lane_tile,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1, lane_tile), lambda i: (0, i)),
+        in_specs=[spec],
+        out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((1, lanes), jnp.uint32),
         interpret=interpret,
-    )(head)
+    )(head.reshape(1, lanes))
     return out[0]
 
 
-def _pop_table_kernel(head_ref, table_ref, feed_ref,
-                      out_head_ref, syms_ref, reads_ref, *, precision: int):
-    """Multi-step table-driven pop for one lane tile.
+# ---------------------------------------------------------------------------
+# decode: one renormalization loop, three symbol searches
+# ---------------------------------------------------------------------------
 
-    Decodes ``steps`` symbols per lane against a static per-lane
-    cumulative-starts table (uint32[LANE_TILE, A+1]). The data-dependent
-    renormalization *read* is fed from ``feed_ref`` - the next ``steps``
-    chunks of each lane's stack pre-gathered outside the kernel in pop
-    order (each pop reads at most one chunk, so ``steps`` rows suffice) -
-    indexed by a per-lane read counter. The symbol search is branchless:
-    ``sym = #(F <= slot) - 1``, ``start = max F <= slot``, ``next = min
-    F > slot``, all lane-parallel reductions over the table axis.
+def _feed_chunk(feed_ref, r):
+    """``feed[r[l], l]`` per lane, by compare-and-select over the 8-row
+    groups that hold the tile's read counters (``r`` int32[1, tile])."""
+    tile = r.shape[-1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (8, tile), 0)
+
+    def group(g, acc):
+        base = pl.multiple_of(g * 8, 8)
+        block = feed_ref[pl.ds(base, 8), :].astype(jnp.int32)
+        hit = rows + base == r
+        return acc + jnp.sum(jnp.where(hit, block, 0), axis=0,
+                             keepdims=True)
+
+    acc = jax.lax.fori_loop(jnp.min(r) // 8, jnp.max(r) // 8 + 1, group,
+                            jnp.zeros_like(r))
+    return acc.astype(jnp.uint32)
+
+
+def table_search(table, slot, total: int):
+    """Compare-and-count over cumulative starts ``table`` int32[A+1,
+    tile] for ``slot`` uint32[1, tile] -> (sym, start, freq) uint32."""
+    s = slot.astype(jnp.int32)
+    le = table <= s
+    sym = jnp.sum(le.astype(jnp.int32), axis=0, keepdims=True) - 1
+    start = jnp.max(jnp.where(le, table, 0), axis=0, keepdims=True)
+    nxt = jnp.min(jnp.where(le, total, table), axis=0, keepdims=True)
+    return (sym.astype(jnp.uint32), start.astype(jnp.uint32),
+            (nxt - start).astype(jnp.uint32))
+
+
+def _pop_kernel(*refs, search: str, precision: int, steps: int, st: int,
+                shift: int = 0):
+    """One (lane tile, step block) of multi-step decoding.
+
+    ``search`` picks how a slot maps to (sym, start, freq): ``"table"``
+    (one static int32[A+1, tile] table), ``"dyn"`` (int32[st, A+1,
+    tile], a table per step) or ``"uniform"`` (closed form, ``shift =
+    precision - bits``). The renormalization read is fed from
+    ``feed_ref`` (uint32[steps8, tile]): the chunks each lane's stack
+    would serve, in pop order (each pop reads at most one). Heads and
+    read counters stay in their output blocks across step blocks.
     """
-    steps = feed_ref.shape[0]
-    total = jnp.uint32(1 << precision)
-    mask = jnp.uint32((1 << precision) - 1)
-    table = table_ref[...]   # uint32[LANE_TILE, A+1]
-    feed = feed_ref[...]     # uint32[steps, LANE_TILE]
+    if search == "uniform":
+        head_ref, feed_ref, out_head_ref, syms_ref, reads_ref = refs
+    else:
+        (head_ref, feed_ref, table_ref,
+         out_head_ref, syms_ref, reads_ref) = refs
+    total = 1 << precision
+    mask = jnp.uint32(total - 1)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_head_ref[...] = head_ref[...]
+        reads_ref[...] = jnp.zeros(reads_ref.shape, jnp.int32)
+
+    if search == "table":
+        table = table_ref[...]
 
     def body(t, carry):
         head, r = carry
         slot = head & mask
-        le = table <= slot[:, None]
-        syms_ref[t, :] = jnp.sum(le, axis=1).astype(jnp.uint32) - 1
-        start = jnp.max(jnp.where(le, table, jnp.uint32(0)), axis=1)
-        nxt = jnp.min(jnp.where(le, total, table), axis=1)
-        head = (nxt - start) * (head >> precision) + slot - start
+        if search == "uniform":
+            sym = slot >> shift
+            start = sym << shift
+            freq = jnp.full_like(start, jnp.uint32(1 << shift))
+        elif search == "table":
+            sym, start, freq = table_search(table, slot, total)
+        else:
+            sym, start, freq = table_search(table_ref[t], slot, total)
+        syms_ref[pl.ds(t, 1), :] = sym
+        head = freq * (head >> precision) + slot - start
         need = head < jnp.uint32(1 << 16)
-        chunk = jnp.take_along_axis(feed, r[None, :], axis=0)[0]
-        head = jnp.where(need, (head << 16) | chunk, head)
+        head = jnp.where(need, (head << 16) | _feed_chunk(feed_ref, r),
+                         head)
         return head, r + need.astype(jnp.int32)
 
-    head0 = head_ref[...]
-    reads0 = jnp.zeros(head0.shape, jnp.int32)
-    head, reads = jax.lax.fori_loop(0, steps, body, (head0, reads0))
+    head, reads = jax.lax.fori_loop(0, _block_len(steps, st), body,
+                                    (out_head_ref[...], reads_ref[...]))
     out_head_ref[...] = head
-    reads_ref[...] = reads.astype(jnp.uint32)
+    reads_ref[...] = reads
+
+
+def _pop_call(head, feed, table, *, search: str, precision: int,
+              interpret: bool, lane_tile: int, shift: int = 0):
+    """Shared pallas_call plumbing of the decode kernels.
+
+    ``table``: None (uniform), int32[A+1, lanes] (static) or
+    int32[steps, A+1, lanes] (per step). Returns (new_head uint32[lanes],
+    syms uint32[steps, lanes], reads uint32[lanes]).
+    """
+    steps, lanes = feed.shape
+    _check_lanes(lanes, lane_tile)
+    # Per step: one syms row, plus a table when it changes every step
+    # (VMEM pads its A+1 rows to the 8-row sublane tile).
+    rows = 1 + (_round_up(table.shape[1], 8) if search == "dyn" else 0)
+    row_bytes = rows * lane_tile * 4
+    st = _step_block(steps, row_bytes)
+    padded = _round_up(steps, st)
+    steps8 = _round_up(steps, 8)
+    resident = steps8 * lane_tile * 4
+    head_spec = pl.BlockSpec((1, lane_tile), lambda i, j: (0, i))
+    in_specs = [head_spec,
+                pl.BlockSpec((steps8, lane_tile), lambda i, j: (0, i))]
+    args = [head.reshape(1, lanes), _pad_steps(feed, steps8)]
+    if search == "table":
+        a1 = table.shape[0]
+        in_specs.append(pl.BlockSpec((a1, lane_tile), lambda i, j: (0, i)))
+        args.append(table)
+        resident += _round_up(a1, 8) * lane_tile * 4
+    elif search == "dyn":
+        a1 = table.shape[1]
+        in_specs.append(pl.BlockSpec((st, a1, lane_tile),
+                                     lambda i, j: (j, 0, i)))
+        args.append(_pad_steps(table, padded))
+    kernel = functools.partial(_pop_kernel, search=search,
+                               precision=precision, steps=steps, st=st,
+                               shift=shift)
+    new_head, syms, reads = pl.pallas_call(
+        kernel,
+        grid=(lanes // lane_tile, padded // st),
+        in_specs=in_specs,
+        out_specs=[head_spec,
+                   pl.BlockSpec((st, lane_tile), lambda i, j: (j, i)),
+                   head_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((1, lanes), jnp.uint32),
+            jax.ShapeDtypeStruct((padded, lanes), jnp.uint32),
+            jax.ShapeDtypeStruct((1, lanes), jnp.int32),
+        ],
+        compiler_params=_compiler_params(st * row_bytes, resident),
+        interpret=interpret,
+    )(*args)
+    return new_head[0], syms[:steps], reads[0].astype(jnp.uint32)
 
 
 def pop_table_emit(head: jnp.ndarray, table: jnp.ndarray,
@@ -166,181 +334,37 @@ def pop_table_emit(head: jnp.ndarray, table: jnp.ndarray,
     serve (top first, clamped at the bottom - see ops.pop_many). lanes
     must be a multiple of ``lane_tile`` (ops.py pads).
     """
-    steps, lanes = feed.shape
-    _check_lanes(lanes, lane_tile)
-    grid = (lanes // lane_tile,)
-    a1 = table.shape[1]
-    kernel = functools.partial(_pop_table_kernel, precision=precision)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-            pl.BlockSpec((lane_tile, a1), lambda i: (i, 0)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((lanes,), jnp.uint32),
-            jax.ShapeDtypeStruct((steps, lanes), jnp.uint32),
-            jax.ShapeDtypeStruct((lanes,), jnp.uint32),
-        ],
-        interpret=interpret,
-    )(head, table, feed)
-
-
-def _pop_dyntable_kernel(head_ref, tables_ref, feed_ref,
-                         out_head_ref, syms_ref, reads_ref, *,
-                         precision: int):
-    """Multi-step pop against *per-step* dynamic tables.
-
-    Like ``_pop_table_kernel`` but the cumulative-starts table changes
-    every step (uint32[steps, LANE_TILE, A+1]) - the decode twin of the
-    dynamic ``_push_kernel``, used by the codec compiler for per-position
-    Bernoulli/Categorical/BetaBinomial leaves whose parameters vary along
-    the ``Repeat`` axis.
-    """
-    steps = feed_ref.shape[0]
-    total = jnp.uint32(1 << precision)
-    mask = jnp.uint32((1 << precision) - 1)
-    feed = feed_ref[...]     # uint32[steps, LANE_TILE]
-
-    def body(t, carry):
-        head, r = carry
-        slot = head & mask
-        table = tables_ref[t]                    # uint32[LANE_TILE, A+1]
-        le = table <= slot[:, None]
-        syms_ref[t, :] = jnp.sum(le, axis=1).astype(jnp.uint32) - 1
-        start = jnp.max(jnp.where(le, table, jnp.uint32(0)), axis=1)
-        nxt = jnp.min(jnp.where(le, total, table), axis=1)
-        head = (nxt - start) * (head >> precision) + slot - start
-        need = head < jnp.uint32(1 << 16)
-        chunk = jnp.take_along_axis(feed, r[None, :], axis=0)[0]
-        head = jnp.where(need, (head << 16) | chunk, head)
-        return head, r + need.astype(jnp.int32)
-
-    head0 = head_ref[...]
-    reads0 = jnp.zeros(head0.shape, jnp.int32)
-    head, reads = jax.lax.fori_loop(0, steps, body, (head0, reads0))
-    out_head_ref[...] = head
-    reads_ref[...] = reads.astype(jnp.uint32)
+    return _pop_call(head, feed, table.astype(jnp.int32).T, search="table",
+                     precision=precision, interpret=interpret,
+                     lane_tile=lane_tile)
 
 
 def pop_dyntable_emit(head: jnp.ndarray, tables: jnp.ndarray,
                       feed: jnp.ndarray, precision: int,
                       interpret: bool = True, lane_tile: int = LANE_TILE):
-    """head uint32[lanes]; tables uint32[steps, lanes, A+1]; feed
-    uint32[steps, lanes] -> (new_head, syms uint32[steps, lanes],
-    reads uint32[lanes]). lanes must be a multiple of ``lane_tile``."""
-    steps, lanes = feed.shape
-    _check_lanes(lanes, lane_tile)
-    grid = (lanes // lane_tile,)
-    a1 = tables.shape[2]
-    kernel = functools.partial(_pop_dyntable_kernel, precision=precision)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-            pl.BlockSpec((steps, lane_tile, a1), lambda i: (0, i, 0)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((lanes,), jnp.uint32),
-            jax.ShapeDtypeStruct((steps, lanes), jnp.uint32),
-            jax.ShapeDtypeStruct((lanes,), jnp.uint32),
-        ],
-        interpret=interpret,
-    )(head, tables, feed)
+    """head uint32[lanes]; tables uint32[steps, lanes, A+1] (one table
+    per step per lane - the decode twin of the dynamic ``push_emit``,
+    used by the codec compiler for per-position Bernoulli/Categorical/
+    BetaBinomial leaves); feed uint32[steps, lanes] -> (new_head, syms
+    uint32[steps, lanes], reads uint32[lanes]). lanes must be a multiple
+    of ``lane_tile``."""
+    return _pop_call(head, feed,
+                     jnp.swapaxes(tables.astype(jnp.int32), 1, 2),
+                     search="dyn", precision=precision,
+                     interpret=interpret, lane_tile=lane_tile)
 
 
-def _pop_grid_kernel(head_ref, mu_ref, sigma_ref, feed_ref, edges_ref,
-                     out_head_ref, idx_ref, reads_ref, *, kind: str,
-                     lat_bits: int, precision: int):
-    """Fused bucketize + pop over the max-entropy N(0,1) bucket grid.
+def grid_cdf_table(mu: jnp.ndarray, sigma: jnp.ndarray, edges: jnp.ndarray,
+                   kind: str, lat_bits: int, precision: int) -> jnp.ndarray:
+    """The fixed-point grid CDF ``F(i)``, i = 0..K, of every (step, lane)
+    as int32[steps, K+1, lanes], from the canonical starts expression
+    the XLA twins bisect pointwise."""
+    from repro.kernels.ans.xla import _grid_starts_fn
 
-    The CDF inversion of ``DiscretizedGaussian``/``DiscretizedLogistic``
-    (the ``kernels/bucketize`` bisection) runs *inside* the ANS pop
-    renormalization chain: per step, slot -> bisection over the
-    pointwise fixed-point CDF -> state update -> masked chunk read.
-    ``kind`` selects the CDF (``gaussian`` via ndtr, ``logistic`` via
-    sigmoid, ``uniform`` closed-form, no bisection); the shared bucket
-    edge table ``z[i] = ndtri(i/K)`` sits once in VMEM. Bit-exact vs
-    the per-position leaves by construction (same edge expression, same
-    primitives, same iteration count - tested against ref.py).
-    """
-    from jax.scipy.special import ndtr
-
-    steps = feed_ref.shape[0]
-    k = 1 << lat_bits
-    scale = float((1 << precision) - k)
-    shift = precision - lat_bits
-    mask = jnp.uint32((1 << precision) - 1)
-    feed = feed_ref[...]     # uint32[steps, LANE_TILE]
-
-    def starts_fn(t):
-        mu = mu_ref[t, :]
-        sigma = sigma_ref[t, :]
-
-        def f(i):
-            # Reciprocal-multiply standardization: the canonical
-            # bit-stable form shared with core.discretize/codecs.leaves.
-            z = edges_ref[i]
-            if kind == "gaussian":
-                c = ndtr((z - mu) * (1.0 / sigma))
-            else:  # logistic: sigma carries the scale parameter
-                c = jax.nn.sigmoid((z - mu) * (1.0 / sigma))
-                c = jnp.clip(c, 0.0, 1.0)
-            c = jnp.where(i <= 0, 0.0, c)
-            c = jnp.where(i >= k, 1.0, c)
-            return jnp.floor(c * scale).astype(jnp.uint32) \
-                + i.astype(jnp.uint32)
-
-        return f
-
-    def body(t, carry):
-        head, r = carry
-        slot = head & mask
-        if kind == "uniform":
-            idx = (slot >> shift).astype(jnp.int32)
-            start = idx.astype(jnp.uint32) << shift
-            freq = jnp.full_like(start, jnp.uint32(1 << shift))
-        else:
-            f = starts_fn(t)
-            lo = jnp.zeros(slot.shape, jnp.int32)
-            hi = jnp.full(slot.shape, k, jnp.int32)
-
-            def bisect(_, lohi):
-                lo, hi = lohi
-                mid = (lo + hi + 1) // 2
-                up = f(mid) <= slot
-                return jnp.where(up, mid, lo), jnp.where(up, hi, mid)
-
-            lo, hi = jax.lax.fori_loop(0, lat_bits + 1, bisect, (lo, hi))
-            idx = lo
-            start = f(idx)
-            freq = f(idx + 1) - start
-        idx_ref[t, :] = idx.astype(jnp.uint32)
-        head = freq * (head >> precision) + slot - start
-        need = head < jnp.uint32(1 << 16)
-        chunk = jnp.take_along_axis(feed, r[None, :], axis=0)[0]
-        head = jnp.where(need, (head << 16) | chunk, head)
-        return head, r + need.astype(jnp.int32)
-
-    head0 = head_ref[...]
-    reads0 = jnp.zeros(head0.shape, jnp.int32)
-    head, reads = jax.lax.fori_loop(0, steps, body, (head0, reads0))
-    out_head_ref[...] = head
-    reads_ref[...] = reads.astype(jnp.uint32)
+    f = _grid_starts_fn(mu[:, None, :], sigma[:, None, :], edges, kind,
+                        lat_bits, precision)
+    i = jnp.arange((1 << lat_bits) + 1, dtype=jnp.int32)[None, :, None]
+    return f(i).astype(jnp.int32)
 
 
 def pop_grid_emit(head: jnp.ndarray, mu: jnp.ndarray, sigma: jnp.ndarray,
@@ -351,39 +375,20 @@ def pop_grid_emit(head: jnp.ndarray, mu: jnp.ndarray, sigma: jnp.ndarray,
     uint32[steps, lanes]; edges float32[K+1] -> (new_head, idx
     uint32[steps, lanes], reads uint32[lanes]).
 
-    ``kind`` in {"gaussian", "logistic", "uniform"}; for uniform the
-    mu/sigma/edges contents are ignored (pass zero-size-compatible
-    dummies). lanes must be a multiple of ``lane_tile`` (ops.py pads).
+    Decodes max-entropy grid buckets: ``kind="uniform"`` in closed form
+    inside the kernel (mu/sigma/edges ignored); ``"gaussian"`` (ndtr)
+    and ``"logistic"`` (sigmoid; sigma carries the scale) through
+    ``grid_cdf_table`` and the dynamic-table kernel. lanes must be a
+    multiple of ``lane_tile`` (ops.py pads).
     """
     if kind not in ("gaussian", "logistic", "uniform"):
         raise ValueError(
             f"kernels.ans: unknown grid kind {kind!r} (expected "
             "'gaussian', 'logistic', or 'uniform')")
-    steps, lanes = feed.shape
-    _check_lanes(lanes, lane_tile)
-    grid = (lanes // lane_tile,)
-    e = edges.shape[0]
-    kernel = functools.partial(_pop_grid_kernel, kind=kind,
-                               lat_bits=lat_bits, precision=precision)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-            pl.BlockSpec((e,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-            pl.BlockSpec((steps, lane_tile), lambda i: (0, i)),
-            pl.BlockSpec((lane_tile,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((lanes,), jnp.uint32),
-            jax.ShapeDtypeStruct((steps, lanes), jnp.uint32),
-            jax.ShapeDtypeStruct((lanes,), jnp.uint32),
-        ],
-        interpret=interpret,
-    )(head, mu, sigma, feed, edges)
+    if kind == "uniform":
+        return _pop_call(head, feed, None, search="uniform",
+                         precision=precision, interpret=interpret,
+                         lane_tile=lane_tile, shift=precision - lat_bits)
+    table = grid_cdf_table(mu, sigma, edges, kind, lat_bits, precision)
+    return _pop_call(head, feed, table, search="dyn", precision=precision,
+                     interpret=interpret, lane_tile=lane_tile)

@@ -3,8 +3,8 @@
 ``push_many`` is the production batch-encode path: the ALU-bound coder
 loop runs in whichever backend ``kernels.dispatch`` resolves - the
 pure-XLA twin (``xla.py``, the CPU fast path: no lane padding, tunable
-unroll), the compiled Pallas kernel (``kernel.py`` on TPU/GPU), or the
-Pallas interpreter as the last-resort oracle - and the irregular
+unroll), the compiled Pallas kernel (``kernel.py``, TPU only), or the
+Pallas interpreter when a test names it - and the irregular
 per-lane stack append becomes one vectorized cumsum + scatter.
 ``pop_many`` is its decode twin: the table search and state updates run
 in the selected backend against a pre-gathered chunk feed (each pop

@@ -9,16 +9,16 @@ loop straight through XLA instead of ``pl.pallas_call``:
     codec-compile workload does 32x the useful work);
   * the whole lane axis is one vector per step instead of a grid of
     tiles, so there is no interpreter masking/copy overhead when the
-    platform has no Mosaic/Triton lowering (CPU);
+    platform has no Mosaic lowering (CPU);
   * an ``unroll`` knob forwards to ``lax.fori_loop`` - the lane-tiling
     autotuner (``kernels.tuning``) measures candidate unroll factors
     per (op, platform, shape) and persists the winner.
 
-Bit-exactness: the loop bodies are copied expression-for-expression
-from ``kernel.py`` (integer renorm arithmetic is exact in any fusion
-context; the grid CDF chain is the canonical reciprocal-multiply form
-shared with ``core.discretize``, stable under fusion by the PR-4
-determinism contract). ``tests/test_dispatch.py`` pins every backend
+Bit-exactness: the integer renorm arithmetic matches ``kernel.py``
+step for step (exact in any fusion context); the grid CDF chain is the
+canonical reciprocal-multiply form shared with ``core.discretize``,
+stable under fusion by the PR-4 determinism contract, and is also
+what builds the Pallas grid pop's table. ``tests/test_dispatch.py`` pins every backend
 to the ``ref.py`` oracles and to the committed golden wires.
 """
 
@@ -119,9 +119,9 @@ def pop_dyntable_emit(head: jnp.ndarray, tables: jnp.ndarray,
 def _grid_starts_fn(mu_t, sigma_t, edges, kind: str, lat_bits: int,
                     precision: int):
     """The canonical grid CDF chain for one step's (mu, sigma) row -
-    expression-identical to ``kernel._pop_grid_kernel``'s ``starts_fn``
-    (and to ``core.discretize``), so every backend gathers one set of
-    bits."""
+    expression-identical to ``core.discretize``; ``kernel.
+    grid_cdf_table`` evaluates it over the whole grid for the Pallas
+    path, so every backend gets one set of bits."""
     from jax.scipy.special import ndtr
 
     k = 1 << lat_bits

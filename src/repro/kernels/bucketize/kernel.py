@@ -1,16 +1,15 @@
 """Pallas TPU kernel: fused max-entropy Gaussian posterior decode.
 
 Given per-lane (slot, mu, sigma) and the prior's bucket-edge table
-``z[i] = ndtri(i / K)`` (computed once outside - it is shared by every
-lane, latent dim and datapoint, ~16 KB in VMEM for K = 4096), finds
-``idx = max{i : F(i) <= slot}`` for the pointwise fixed-point posterior
-CDF ``F(i) = floor(ndtr((z[i]-mu)/sigma) * (2^prec - K)) + i`` and
-returns (idx, start, freq) - the per-latent-dim hot loop of BB-ANS
-decode. The bisection is ``lat_bits + 1`` fully-vectorized iterations;
-ndtr lowers to the erfc VPU primitive.
+``z[i] = ndtri(i / K)``, finds ``idx = max{i : F(i) <= slot}`` for the
+pointwise fixed-point posterior CDF ``F(i) = floor(ndtr((z[i]-mu)/sigma)
+* (2^prec - K)) + i`` and returns (idx, start, freq) - the per-latent-dim
+hot loop of BB-ANS decode.
 
-Bit-exact vs ref.py / core.discretize: the edge table is built by the
-same expression the core uses pointwise, and ndtr is the same primitive.
+XLA evaluates ``F`` over the whole grid (``kernels.ans.kernel.
+grid_cdf_table``: Mosaic lowers no ``erf``); the kernel searches it by
+compare-and-count in int32, so its answer is the XLA twin's bisection
+bit for bit (ref.py / core.discretize).
 """
 
 from __future__ import annotations
@@ -20,7 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.scipy.special import ndtr
+
+from repro.kernels.ans import kernel as ans_kernel
 
 LANE_TILE = 128
 
@@ -34,36 +34,13 @@ def edge_table(lat_bits: int) -> jnp.ndarray:
     return discretize.edge_table(lat_bits)
 
 
-def _bucketize_kernel(slot_ref, mu_ref, sigma_ref, edges_ref,
-                      idx_ref, start_ref, freq_ref, *,
-                      lat_bits: int, precision: int):
-    slot = slot_ref[...]
-    mu = mu_ref[...]
-    sigma = sigma_ref[...]
-    k = 1 << lat_bits
-    scale = float((1 << precision) - k)
-
-    def f(i):
-        z = edges_ref[i]  # gather from the shared edge table
-        c = ndtr((z - mu) * (1.0 / sigma))   # canonical form, see core
-        c = jnp.where(i <= 0, 0.0, c)
-        c = jnp.where(i >= k, 1.0, c)
-        return jnp.floor(c * scale).astype(jnp.uint32) + i.astype(jnp.uint32)
-
-    lo = jnp.zeros_like(slot, jnp.int32)
-    hi = jnp.full_like(lo, k)
-
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = (lo + hi + 1) // 2
-        up = f(mid) <= slot
-        return jnp.where(up, mid, lo), jnp.where(up, hi, mid)
-
-    lo, hi = jax.lax.fori_loop(0, lat_bits + 1, body, (lo, hi))
-    start = f(lo)
-    idx_ref[...] = lo
+def _bucketize_kernel(slot_ref, table_ref, idx_ref, start_ref, freq_ref, *,
+                      precision: int):
+    idx, start, freq = ans_kernel.table_search(
+        table_ref[...], slot_ref[...], 1 << precision)
+    idx_ref[...] = idx.astype(jnp.int32)
     start_ref[...] = start
-    freq_ref[...] = f(lo + 1) - start
+    freq_ref[...] = freq
 
 
 def bucketize(slot: jnp.ndarray, mu: jnp.ndarray, sigma: jnp.ndarray,
@@ -76,21 +53,22 @@ def bucketize(slot: jnp.ndarray, mu: jnp.ndarray, sigma: jnp.ndarray,
         raise ValueError(
             f"kernels.bucketize: lanes ({lanes}) must be a multiple of "
             f"lane_tile ({lane_tile}); ops.py pads before calling")
-    k = 1 << lat_bits
-    edges = edge_table(lat_bits)
-    kernel = functools.partial(_bucketize_kernel, lat_bits=lat_bits,
-                               precision=precision)
-    spec = pl.BlockSpec((lane_tile,), lambda i: (i,))
-    return pl.pallas_call(
+    table = ans_kernel.grid_cdf_table(
+        mu[None], sigma[None], edge_table(lat_bits), "gaussian", lat_bits,
+        precision)[0]
+    kernel = functools.partial(_bucketize_kernel, precision=precision)
+    spec = pl.BlockSpec((1, lane_tile), lambda i: (0, i))
+    idx, start, freq = pl.pallas_call(
         kernel,
         grid=(lanes // lane_tile,),
-        in_specs=[spec, spec, spec,
-                  pl.BlockSpec((k + 1,), lambda i: (0,))],
+        in_specs=[spec, pl.BlockSpec((table.shape[0], lane_tile),
+                                     lambda i: (0, i))],
         out_specs=[spec, spec, spec],
         out_shape=[
-            jax.ShapeDtypeStruct((lanes,), jnp.int32),
-            jax.ShapeDtypeStruct((lanes,), jnp.uint32),
-            jax.ShapeDtypeStruct((lanes,), jnp.uint32),
+            jax.ShapeDtypeStruct((1, lanes), jnp.int32),
+            jax.ShapeDtypeStruct((1, lanes), jnp.uint32),
+            jax.ShapeDtypeStruct((1, lanes), jnp.uint32),
         ],
         interpret=interpret,
-    )(slot, mu, sigma, edges)
+    )(slot.reshape(1, lanes), table)
+    return idx[0], start[0], freq[0]

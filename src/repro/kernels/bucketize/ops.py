@@ -1,7 +1,7 @@
 """Dispatched wrapper for the fused posterior-decode bucketize op.
 
 Backend selection follows ``kernels.dispatch`` (XLA twin on CPU,
-compiled Pallas on accelerators, interpreter as oracle); the Pallas
+compiled Pallas on TPU, the interpreter only by name); the Pallas
 paths pad lanes to the decision's tile width, the XLA path runs the
 caller's lane count as-is.
 """
@@ -23,10 +23,10 @@ def bucketize(slot, mu, sigma, lat_bits, precision,
     backend."""
     lanes = slot.shape[0]
     d = dispatch.resolve("bucketize", lanes=lanes, backend=backend)
+    mu, sigma = mu.astype(jnp.float32), sigma.astype(jnp.float32)
     if d.backend == "xla":
-        return X.bucketize(slot, mu.astype(jnp.float32),
-                           sigma.astype(jnp.float32),
-                           K.edge_table(lat_bits), lat_bits, precision)
+        return X.bucketize(slot, mu, sigma, K.edge_table(lat_bits),
+                           lat_bits, precision)
     pad = (-lanes) % d.lane_tile
     if pad:
         slot = jnp.pad(slot, (0, pad))

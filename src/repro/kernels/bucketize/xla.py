@@ -4,8 +4,8 @@ Same contract as ``kernel.bucketize`` - (slot, mu, sigma) per lane plus
 the shared edge table -> (idx, start, freq) - but the bisection runs as
 straight-line XLA over the caller's lane count: no LANE_TILE padding,
 no Pallas interpreter. The CDF chain is expression-identical to
-``kernel._bucketize_kernel`` (and ``core.discretize``), so the gathered
-bits match bit-for-bit on every backend.
+``kernels.ans.xla._grid_starts_fn`` (which builds the Pallas path's
+table) and ``core.discretize``, so the bits match on every backend.
 """
 
 from __future__ import annotations

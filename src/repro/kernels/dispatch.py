@@ -3,18 +3,18 @@
 Every hot op in ``kernels/ans/ops.py`` and ``kernels/bucketize/ops.py``
 has up to three bit-identical implementations:
 
-  * ``"pallas"``    - ``pl.pallas_call`` compiled through Mosaic (TPU)
-                      or Triton (GPU). Only available when an
-                      accelerator platform is active.
+  * ``"pallas"``    - ``pl.pallas_call`` compiled through Mosaic. Only
+                      available on TPU (the kernels are written for
+                      Mosaic's layouts and compiler parameters).
   * ``"xla"``       - the pure-XLA twins in ``kernels/*/xla.py``: same
                       loop bodies jitted straight through XLA, no lane
                       padding, tunable ``fori_loop`` unroll. The CPU
                       fast path.
   * ``"interpret"`` - ``pl.pallas_call(interpret=True)``: the Pallas
-                      interpreter emulating the kernel. Runs anywhere;
-                      the last-resort oracle and the historical
-                      behaviour of every op before the dispatcher
-                      existed.
+                      interpreter emulating the kernel. Runs anywhere,
+                      slowly: the CPU tests use it to check the
+                      kernels, and it is only ever used when asked
+                      for by name.
 
 ``resolve(op, ...)`` picks one as a :class:`Decision` (backend +
 lane-tile + unroll), with precedence:
@@ -22,8 +22,9 @@ lane-tile + unroll), with precedence:
   1. an explicit ``backend=`` argument (string or ``Decision``),
   2. the ``REPRO_KERNEL_BACKEND`` environment variable,
   3. an enclosing ``with use_backend(...)`` context,
-  4. the persisted tuning cache (``kernels.tuning``, measured once),
-  5. the platform heuristic: ``xla`` on CPU, ``pallas`` on TPU/GPU.
+  4. the persisted tuning cache (``kernels.tuning``, measured once;
+     it never holds ``interpret``),
+  5. the platform heuristic: ``pallas`` on TPU, ``xla`` elsewhere.
 
 Wire bytes never depend on the choice - the parity suite
 (``tests/test_dispatch.py``) pins every available backend to the
@@ -91,12 +92,12 @@ def platform() -> str:
 
 def available_backends(plat: Optional[str] = None) -> Tuple[str, ...]:
     """Backends that can actually run on ``plat`` (default: active
-    platform), best-first. ``pallas`` compiled mode needs a Mosaic or
-    Triton lowering, so it is only offered off-CPU."""
+    platform), best-first. ``pallas`` compiled mode needs the Mosaic
+    lowering, so it is only offered on TPU."""
     p = plat if plat is not None else platform()
-    if p == "cpu":
-        return ("xla", "interpret")
-    return ("pallas", "xla", "interpret")
+    if p == "tpu":
+        return ("pallas", "xla", "interpret")
+    return ("xla", "interpret")
 
 
 def _normalize(backend: BackendLike) -> Optional[Decision]:

@@ -21,7 +21,8 @@ to a versioned JSON cache:
 Nothing here runs implicitly: ``codecs.compile`` only *measures* at
 lowering when ``REPRO_AUTOTUNE`` is set (see ``ensure``); otherwise a
 cache miss falls back to the dispatch heuristic. Candidates never
-include ``interpret`` - it exists as an oracle, not a contender.
+include ``interpret`` - it exists as an oracle, not a contender - and
+the cache neither records nor serves it.
 
 CLI: ``python -m repro.kernels.tuning --lanes 64 --steps 256`` warms
 the cache for every hot op and prints the winning decisions.
@@ -123,8 +124,8 @@ def _key(plat: str, op: str, lanes: Optional[int],
 def lookup(plat: str, op: str, lanes: Optional[int] = None,
            table_size: Optional[int] = None) -> Optional[Decision]:
     """The cached Decision for this signature, or None on miss. Entries
-    naming a backend unavailable on ``plat`` (or malformed entries) are
-    ignored rather than raised."""
+    naming ``interpret`` or a backend unavailable on ``plat`` (or
+    malformed entries) are ignored rather than raised."""
     entry = _load().get(_key(plat, op, lanes, table_size))
     if not isinstance(entry, dict):
         return None
@@ -135,7 +136,8 @@ def lookup(plat: str, op: str, lanes: Optional[int] = None,
             unroll=int(entry.get("unroll", 1)))
     except (KeyError, TypeError, ValueError):
         return None
-    if decision.backend not in available_backends(plat):
+    if decision.backend == "interpret" \
+            or decision.backend not in available_backends(plat):
         return None
     return decision
 
@@ -144,7 +146,10 @@ def record(plat: str, op: str, decision: Decision, ms: float,
            lanes: Optional[int] = None,
            table_size: Optional[int] = None) -> None:
     """Persist a measured winner (atomic write, updates the in-process
-    view)."""
+    view). ``interpret`` is refused: it is never a measured winner."""
+    if decision.backend == "interpret":
+        raise ValueError("kernels.tuning: the interpret backend is an "
+                         "oracle and is never recorded as a winner")
     entries = _load()
     entries[_key(plat, op, lanes, table_size)] = {
         "backend": decision.backend,
@@ -248,23 +253,15 @@ def autotune_op(op: str, lanes: int, steps: int = 256,
                 table_size: int = 16, lat_bits: int = 6,
                 precision: int = 14, reps: int = 3) -> Decision:
     """Time every candidate for ``op`` on a representative workload,
-    persist the winner, and return it. Candidates that fail to compile
-    (e.g. a Pallas lowering gap) are skipped, not raised."""
+    persist the winner, and return it. A candidate that fails to
+    compile or run raises: every candidate is a supported path."""
     plat = platform()
     tsize = table_size if op in ("push_many_table", "pop_many",
                                  "pop_many_dyn") else None
     fn = _workload(op, lanes, steps, table_size, lat_bits, precision)
-    best: Optional[Decision] = None
-    best_ms = float("inf")
-    for decision in candidates(plat):
-        try:
-            ms = _time_ms(lambda d=decision: fn(d), reps=reps)
-        except Exception:   # noqa: BLE001 - a losing candidate, not a bug
-            continue
-        if ms < best_ms:
-            best, best_ms = decision, ms
-    if best is None:       # nothing compiled: fall back to the oracle
-        best, best_ms = Decision("interpret"), 0.0
+    timed = [(_time_ms(lambda d=d: fn(d), reps=reps), i, d)
+             for i, d in enumerate(candidates(plat))]
+    best_ms, _, best = min(timed)
     record(plat, op, best, best_ms, lanes=lanes, table_size=tsize)
     return best
 

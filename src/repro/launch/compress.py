@@ -34,10 +34,47 @@ import numpy as np
 from repro import shard_codec
 from repro.data import baselines as baseline_lib
 from repro.data import synthetic_mnist
+from repro.launch import compile_cache
 from repro.models import vae as vae_lib
 from repro.optim import adamw
 
 ARCHS = ("vae-bernoulli", "vae-beta_binomial", "hvae-small2")
+
+
+def train_vae(cfg: vae_lib.VAEConfig, *, steps: int, seed: int = 0,
+              n_train: int = 8000, batch: int = 128, lr: float = 1e-3):
+    """Train the paper's VAE on synthetic MNIST; returns ``(params,
+    test -ELBO in bits/dim)``."""
+    binary = cfg.likelihood == "bernoulli"
+    train_imgs, _ = synthetic_mnist.load("train", n_train, seed)
+    if binary:
+        train_imgs = synthetic_mnist.binarize(train_imgs, seed)
+    test_imgs, _ = synthetic_mnist.load("test", 1024, seed)
+    if binary:
+        test_imgs = synthetic_mnist.binarize(test_imgs, seed + 1)
+    params = vae_lib.init(jax.random.PRNGKey(seed), cfg)
+    opt = adamw.AdamW(learning_rate=adamw.cosine_lr(lr, 100, steps))
+    state = opt.init(params)
+
+    @jax.jit
+    def step(params, state, key, imgs):
+        loss, grads = jax.value_and_grad(vae_lib.loss)(
+            params, cfg, key, imgs)
+        params, state = opt.update(grads, state, params)
+        return params, state, loss
+
+    rng = np.random.default_rng(seed)
+    key = jax.random.PRNGKey(seed + 1)
+    for _ in range(steps):
+        idx = rng.integers(0, len(train_imgs), batch)
+        key, sub = jax.random.split(key)
+        params, state, _ = step(
+            params, state, sub, jnp.asarray(train_imgs[idx], jnp.int32))
+    keys = jax.random.split(jax.random.PRNGKey(seed + 2), 4)
+    elbo = float(np.mean([float(vae_lib.elbo_bits_per_dim(
+        params, cfg, k, jnp.asarray(test_imgs, jnp.int32)))
+        for k in keys]))
+    return params, elbo
 
 
 def train_dataset_model(arch: str, *, steps: int, seed: int = 0,
@@ -51,37 +88,10 @@ def train_dataset_model(arch: str, *, steps: int, seed: int = 0,
     """
     if arch.startswith("vae-"):
         cfg = vae_lib.paper_config(arch.split("-", 1)[1])
-        binary = cfg.likelihood == "bernoulli"
-        train_imgs, _ = synthetic_mnist.load("train", n_train, seed)
-        if binary:
-            train_imgs = synthetic_mnist.binarize(train_imgs, seed)
-        test_imgs, _ = synthetic_mnist.load("test", 1024, seed)
-        if binary:
-            test_imgs = synthetic_mnist.binarize(test_imgs, seed + 1)
-        params = vae_lib.init(jax.random.PRNGKey(seed), cfg)
-        opt = adamw.AdamW(learning_rate=adamw.cosine_lr(lr, 100, steps))
-        state = opt.init(params)
-
-        @jax.jit
-        def step(params, state, key, imgs):
-            loss, grads = jax.value_and_grad(vae_lib.loss)(
-                params, cfg, key, imgs)
-            params, state = opt.update(grads, state, params)
-            return params, state, loss
-
-        rng = np.random.default_rng(seed)
-        key = jax.random.PRNGKey(seed + 1)
-        for _ in range(steps):
-            idx = rng.integers(0, len(train_imgs), batch)
-            key, sub = jax.random.split(key)
-            params, state, _ = step(
-                params, state, sub, jnp.asarray(train_imgs[idx],
-                                                jnp.int32))
-        keys = jax.random.split(jax.random.PRNGKey(seed + 2), 4)
-        elbo = float(np.mean([float(vae_lib.elbo_bits_per_dim(
-            params, cfg, k, jnp.asarray(test_imgs, jnp.int32)))
-            for k in keys]))
-        return (lambda: vae_lib.make_bb_codec(params, cfg)), binary, elbo
+        params, elbo = train_vae(cfg, steps=steps, seed=seed,
+                                 n_train=n_train, batch=batch, lr=lr)
+        return ((lambda: vae_lib.make_bb_codec(params, cfg)),
+                cfg.likelihood == "bernoulli", elbo)
 
     if arch == "hvae-small2":
         from repro.configs import hvae_img
@@ -139,11 +149,12 @@ def load_corpus(arch: str, n_images: int, lanes: int,
 
 def compress_corpus(codec, data, *, n_shards: int, block_symbols: int,
                     seed: int, init_chunks: int = 32,
-                    compile: bool = True) -> bytes:
-    """``shard_codec.compress_dataset`` with the CLI's defaults."""
+                    compile: bool = True, **kwargs):
+    """``shard_codec.compress_dataset`` with the CLI's defaults
+    (``kwargs`` such as ``devices`` or ``with_info`` pass through)."""
     return shard_codec.compress_dataset(
         codec, data, n_shards=n_shards, block_symbols=block_symbols,
-        seed=seed, init_chunks=init_chunks, compile=compile)
+        seed=seed, init_chunks=init_chunks, compile=compile, **kwargs)
 
 
 def main():
@@ -164,6 +175,7 @@ def main():
     ap.add_argument("--skip-decode", action="store_true",
                     help="skip the lossless full-decode verification")
     args = ap.parse_args()
+    compile_cache.enable_compile_cache()
 
     n_shards = args.shards or len(jax.devices())
     if args.lanes % n_shards:

@@ -8,17 +8,7 @@ module never touches jax device state - required by the dry-run, whose
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` /
-    ``jax.sharding.AxisType`` only exist in newer releases (the default
-    there - Auto - matches the older behaviour)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,10 +16,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """All local devices on a single 'data' axis (tests, examples)."""
     n = len(jax.devices())
-    return make_mesh_compat((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))

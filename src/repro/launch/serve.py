@@ -38,6 +38,7 @@ import numpy as np
 from repro import codecs, stream
 from repro.configs import base as cfg_base
 from repro.data import tokens as tok_data
+from repro.launch import compile_cache
 from repro.models import transformer
 from repro.serve.engine import Engine
 
@@ -95,6 +96,7 @@ def main():
                          "kernel programs; byte-identical wire)")
     ap.add_argument("--kv-dtype", default="bfloat16")
     args = ap.parse_args()
+    compile_cache.enable_compile_cache()
 
     if args.mode == "hvae":
         return main_hvae(args)

@@ -35,19 +35,6 @@ ROUTER_Z_COEF = 1e-3
 LOAD_BALANCE_COEF = 1e-2
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: new releases expose it as
-    ``jax.shard_map`` (replication check flag ``check_vma``), older ones
-    under ``jax.experimental.shard_map`` (flag ``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_old
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 def moe_init(key, cfg):
     d, f, e = cfg.d_model, cfg.expert_d_ff, cfg.n_experts
     ks = jax.random.split(key, 6)
@@ -240,11 +227,11 @@ def _moe_apply_ep(p, x, cfg, mesh, compute_dtype):
         return y, jax.lax.pmean(aux, "model")
 
     wi_spec = P("model", None, None)
-    out = _shard_map(
+    out = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(dp_axes, None, None), P(None, None),
                   wi_spec, wi_spec, wi_spec),
-        out_specs=(P(dp_axes, None, None), P()),
+        out_specs=(P(dp_axes, None, None), P()), check_vma=False,
     )(x, p["router"]["w"], p["wi"], p["wg"], p["wo"])
     y, aux = out
     return _finish(p, x, y, cfg, compute_dtype), aux

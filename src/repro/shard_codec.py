@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
-from typing import Any, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Iterable, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -156,7 +156,8 @@ def compress_dataset(codec: Codec, data: Any, *, n_shards: int,
                      precision: int = ans.DEFAULT_PRECISION,
                      devices: Optional[Sequence[Any]] = None,
                      kernel_backend: Optional[str] = None,
-                     **encoder_kwargs) -> bytes:
+                     with_info: bool = False,
+                     **encoder_kwargs) -> Union[bytes, Tuple[bytes, dict]]:
     """Compress a dataset to one BBX3 corpus blob, lane-parallel.
 
     ``data`` is a ``[n, lanes, ...]`` pytree or an iterable of such
@@ -172,6 +173,12 @@ def compress_dataset(codec: Codec, data: Any, *, n_shards: int,
     seed gives shard ``s`` the derived seed ``seed + s`` for its random
     first heads and per-block clean bits. Extra ``encoder_kwargs``
     (``capacity``, ``compile``, ...) pass through to every encoder.
+
+    ``with_info=True`` returns ``(blob, info)``: ``info["net_bits"]``
+    is the information the encode added (the -ELBO-comparable rate,
+    free of clean-bit and flush constants) and
+    ``info["shard_devices"]`` the device each shard's coder state
+    ended on.
 
     Example::
 
@@ -203,10 +210,14 @@ def compress_dataset(codec: Codec, data: Any, *, n_shards: int,
                 segments[s].extend(encoders[s].write(placed))
         for s, enc in enumerate(encoders):
             segments[s].extend(enc.flush())
-    return fmt.encode_corpus(
+    blob = fmt.encode_corpus(
         [bytes(seg) for seg in segments],
         [enc.n_symbols for enc in encoders],
         lanes_per_shard=encoders[0].lanes, precision=precision)
+    if not with_info:
+        return blob
+    return blob, {"net_bits": sum(enc.net_bits for enc in encoders),
+                  "shard_devices": [enc.device for enc in encoders]}
 
 
 def decompress_shard(codec: Codec, blob: bytes, shard: int,

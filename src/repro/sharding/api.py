@@ -62,7 +62,8 @@ def use_mesh(mesh: Optional[Mesh], rules: Optional[Dict[str, Axes]] = None):
 
     Example::
 
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         with use_mesh(mesh, {"seq": "model"}):
             y = constrain(x, "batch", "seq")   # sharded inside jit
     """
@@ -148,7 +149,8 @@ def resolve(*logical: Optional[str]) -> P:
 
     Example::
 
-        with use_mesh(make_mesh_compat((4,), ("data",))):
+        with use_mesh(jax.make_mesh((4,), ("data",),
+                                    axis_types=(AxisType.Auto,))):
             assert resolve("batch", "embed") == P("data", None)
     """
     rules = _ENV.rules or {}

@@ -325,6 +325,15 @@ class StreamEncoder:
         return self._emit(done)
 
     @property
+    def device(self):
+        """The device holding the carried coder state (``None`` before
+        the first block is encoded)."""
+        if self._heads is None:
+            return None
+        (dev,) = self._heads.devices()
+        return dev
+
+    @property
     def buffered_symbols(self) -> int:
         """Datapoints accepted by ``write`` but not yet on the wire
         (zero exactly at block boundaries, where ``snapshot`` is legal)."""

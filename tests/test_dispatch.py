@@ -173,14 +173,49 @@ def test_tuning_cache_round_trip(tmp_cache):
 
 def test_resolve_precedence_beats_cache(tmp_cache, monkeypatch):
     plat = dispatch.platform()
-    tuning.record(plat, "push_many",
-                  dispatch.Decision("interpret"), 1.0, lanes=8)
-    with dispatch.use_backend("xla"):       # context over cache
-        assert dispatch.resolve("push_many", lanes=8).backend == "xla"
-    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "xla")  # env over both
-    assert dispatch.resolve("push_many", lanes=8).backend == "xla"
-    monkeypatch.delenv("REPRO_KERNEL_BACKEND")
+    cached = dispatch.Decision("xla", unroll=4)
+    tuning.record(plat, "push_many", cached, 1.0, lanes=8)
+    with dispatch.use_backend("interpret"):   # context over cache
+        assert dispatch.resolve("push_many", lanes=8).backend == \
+            "interpret"
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")  # env too
     assert dispatch.resolve("push_many", lanes=8).backend == "interpret"
+    monkeypatch.delenv("REPRO_KERNEL_BACKEND")
+    assert dispatch.resolve("push_many", lanes=8) == cached
+
+
+def test_interpret_is_never_cached_or_resolved_implicitly(tmp_cache):
+    """The tuning cache refuses ``interpret``, ignores a hand-written
+    ``interpret`` entry, and the heuristic never picks it."""
+    plat = dispatch.platform()
+    with pytest.raises(ValueError, match="interpret"):
+        tuning.record(plat, "push_many", dispatch.Decision("interpret"),
+                      1.0, lanes=8)
+    tuning.record(plat, "push_many", dispatch.Decision("xla"), 1.0,
+                  lanes=8)
+    with open(tmp_cache) as f:
+        raw = json.load(f)
+    for entry in raw["entries"].values():
+        entry["backend"] = "interpret"
+    with open(tmp_cache, "w") as f:
+        json.dump(raw, f)
+    tuning.refresh()
+    assert tuning.lookup(plat, "push_many", lanes=8) is None
+    assert dispatch.resolve("push_many", lanes=8).backend != "interpret"
+    for plat_name in ("cpu", "tpu"):
+        assert dispatch.available_backends(plat_name)[0] != "interpret"
+    assert dispatch.available_backends("tpu")[0] == "pallas"
+
+
+def test_autotune_raises_when_a_candidate_fails(tmp_cache, monkeypatch):
+    """A candidate that cannot run is an error, never a silent skip
+    (and never an ``interpret`` fallback)."""
+    def broken(*args, **kwargs):
+        raise RuntimeError("lowering refused")
+    monkeypatch.setattr(tuning, "_time_ms", broken)
+    with pytest.raises(RuntimeError, match="lowering refused"):
+        tuning.autotune_op("push_many", lanes=8, steps=4, reps=1)
+    assert tuning.lookup(dispatch.platform(), "push_many", lanes=8) is None
 
 
 @pytest.mark.parametrize("content", [

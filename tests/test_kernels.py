@@ -284,6 +284,58 @@ def test_ans_pop_grid_underflow_matches_ref():
     assert int(jnp.sum(out_k.underflows)) > 0
 
 
+@pytest.mark.parametrize("op", ["push", "pop_table", "pop_dyn",
+                                "grid_gaussian", "grid_uniform"])
+def test_step_blocked_kernels_match_ref(op, monkeypatch):
+    """A VMEM budget of a few steps splits every multi-step kernel into
+    8-step blocks plus a short tail; the carried heads and read
+    counters must cross the block boundaries bit-exactly."""
+    from repro.kernels.ans import kernel as ans_kernel
+    monkeypatch.setattr(ans_kernel, "_STEP_BLOCK_BYTES", 1)
+    rng = np.random.default_rng(41)
+    steps, lanes, precision = 21, 8, 12
+    stack = ans.seed_stack(
+        ans.make_stack(lanes, 4 * steps, key=jax.random.PRNGKey(42)),
+        jax.random.PRNGKey(43), steps)
+    if op == "push":
+        starts, freqs = _rand_symbol_stream(rng, steps, lanes, 5,
+                                            precision)
+        out_k = ans_ops.push_many(stack, starts, freqs, precision,
+                                  backend="interpret")
+        out_r = ans_ref.push_many_ref(stack, starts, freqs, precision)
+        for field in ("head", "buf", "ptr", "overflows"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(out_k, field)),
+                np.asarray(getattr(out_r, field)))
+        return
+    if op == "pop_table":
+        table = ans.probs_to_starts(jnp.asarray(
+            rng.dirichlet(np.ones(6), size=lanes), jnp.float32), precision)
+        out_k, syms_k = ans_ops.pop_many(stack, table, steps, precision,
+                                         backend="interpret")
+        out_r, syms_r = ans_ref.pop_many_ref(stack, table, steps,
+                                             precision)
+    elif op == "pop_dyn":
+        tables = _dyn_tables(rng, steps, lanes, 6, precision)
+        out_k, syms_k = ans_ops.pop_many_dyn(stack, tables, precision,
+                                             backend="interpret")
+        out_r, syms_r = ans_ref.pop_many_dyn_ref(stack, tables, precision)
+    else:
+        kind = op.split("_")[1]
+        mu = jnp.asarray(rng.normal(0, 1, (steps, lanes)), jnp.float32)
+        sigma = jnp.asarray(rng.uniform(0.1, 1.5, (steps, lanes)),
+                            jnp.float32)
+        out_k, syms_k = ans_ops.pop_many_grid(stack, kind, mu, sigma, steps,
+                                              6, precision,
+                                              backend="interpret")
+        out_r, syms_r = ans_ref.pop_many_grid_ref(stack, kind, mu, sigma,
+                                                  steps, 6, precision)
+    np.testing.assert_array_equal(np.asarray(syms_k), np.asarray(syms_r))
+    for field in ("head", "ptr", "underflows"):
+        np.testing.assert_array_equal(np.asarray(getattr(out_k, field)),
+                                      np.asarray(getattr(out_r, field)))
+
+
 # ---------------------------------------------------------------------------
 # Bucketize kernel
 # ---------------------------------------------------------------------------

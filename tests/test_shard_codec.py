@@ -6,6 +6,7 @@ subprocess (the in-process backend is already initialized 1-device).
 """
 
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -277,6 +278,45 @@ def test_multi_device_wire_matches_single_device():
     one = eng.compress(data)
     assert one.hex() == rec["oneshot"], \
         "one-shot SPMD bytes differ between 8 devices and 1 device"
+
+
+FLOAT_VAE_SPMD_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax, jax.numpy as jnp
+    from repro.launch import compress as cli
+    from repro.models import vae
+    from repro.serve import CodecEngine, ShardedCodecEngine
+
+    cfg = vae.paper_config("bernoulli")
+    codec = vae.make_bb_codec(vae.init(jax.random.PRNGKey(0), cfg), cfg)
+    _, data, _ = cli.load_corpus("vae-bernoulli", 64, 32)
+    sharded = ShardedCodecEngine(lambda s: codec, n_shards=4, seed=0)
+    one = CodecEngine(lambda s: codec, seed=0, compile=True)
+    blob = sharded.compress(data)
+    print(json.dumps({
+        "same": blob == one.compress(data),
+        "lossless": bool(jnp.array_equal(
+            sharded.decompress(blob, 2, (784,)), data)),
+    }))
+""")
+
+
+def test_sharded_engine_float_vae_decodes_on_four_devices():
+    """The float VAE's one-shot SPMD decode is lossless: the model runs
+    on the popped symbols between coder programs, and lane-sharded
+    symbols would let XLA partition its matmuls into other float bits
+    than the encoder saw."""
+    out = subprocess.run(
+        [sys.executable, "-c", FLOAT_VAE_SPMD_SCRIPT],
+        capture_output=True, text=True, timeout=300,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "HOME": os.environ.get("HOME", "/tmp"),
+             "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec == {"same": True, "lossless": True}
 
 
 # ---------------------------------------------------------------------------

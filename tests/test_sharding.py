@@ -14,9 +14,9 @@ import textwrap
 import jax
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import base as cfg_base
-from repro.launch.mesh import make_mesh_compat
 from repro.models import transformer
 from repro.sharding import api as shard_api
 from repro.sharding import policies
@@ -38,7 +38,7 @@ def test_param_specs_cover_every_leaf(arch):
 
 
 def test_resolve_dedups_mesh_axes():
-    mesh = make_mesh_compat((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     with shard_api.use_mesh(mesh, {"seq": "model", "ff": "model"}):
         spec = shard_api.resolve("batch", "seq", "ff")
         used = [e for e in spec if e is not None]
@@ -55,7 +55,7 @@ def test_drop_fsdp():
 
 def test_to_named_drops_nondivisible():
     from jax.sharding import PartitionSpec as P
-    mesh = make_mesh_compat((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     sh = policies.to_named(mesh, P("model"),
                            jax.ShapeDtypeStruct((3,), np.float32))
     # 3 % 1 == 0 -> kept; now a fake 16-way mesh can't be built on CPU,
@@ -70,10 +70,11 @@ SUBPROCESS_SCRIPT = textwrap.dedent("""
     import jax
     from repro.configs import base as cfg_base
     from repro.launch import dryrun
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
     from repro.sharding import api as shard_api
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = dataclasses.replace(
         cfg_base.reduced(cfg_base.get("{arch}")),
         vocab=512, grad_accum=2)

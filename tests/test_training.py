@@ -185,9 +185,8 @@ def test_elastic_restore_resharding(tmp_path):
     (here: host -> explicit single-device sharding)."""
     tree = {"w": jnp.arange(64.0).reshape(8, 8)}
     checkpoint.save(1, tree, str(tmp_path))
-    from repro.launch import mesh as mesh_lib
-    mesh = mesh_lib.make_mesh_compat((1,), ("data",))
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     shardings = {"w": NamedSharding(mesh, P("data", None))}
     out = checkpoint.restore(jax.tree_util.tree_map(jnp.zeros_like, tree),
                              str(tmp_path), shardings=shardings)
