@@ -70,6 +70,7 @@ from repro.core.distributions import (Bernoulli, BetaBinomial, Categorical,
 from repro.codecs import combinators as C
 from repro.codecs import leaves as L
 from repro.codecs import quantize as Q
+from repro import spans
 from repro.kernels import dispatch
 from repro.kernels.ans import ops as ans_ops
 
@@ -289,6 +290,7 @@ class _GridRepeat(Codec):
     out_dtype: Any = jnp.int32
     donate: bool = True
 
+    @spans.spanned(spans.CODER)
     def push(self, stack: ans.ANSStack, x: jnp.ndarray) -> ans.ANSStack:
         idx = x.astype(jnp.int32).T                       # [n, lanes]
         mu = self.mu if self.mu is not None else jnp.zeros(())
@@ -298,6 +300,7 @@ class _GridRepeat(Codec):
             stack, idx, mu, sigma, kind=self.kind, bits=self.bits,
             precision=self.precision, backend=d)
 
+    @spans.spanned(spans.CODER)
     def pop(self, stack: ans.ANSStack):
         mu = self.mu if self.mu is not None else jnp.zeros(())
         sigma = self.sigma if self.sigma is not None else jnp.zeros(())
@@ -323,6 +326,7 @@ class _TableRepeat(Codec):
     out_dtype: Any = jnp.int32
     donate: bool = True
 
+    @spans.spanned(spans.CODER)
     def push(self, stack: ans.ANSStack, x: jnp.ndarray) -> ans.ANSStack:
         symT = x.astype(jnp.int32).T                      # [n, lanes]
         d = dispatch.resolve("push_many_table", lanes=stack.lanes,
@@ -331,6 +335,7 @@ class _TableRepeat(Codec):
             stack, self.tables, symT, precision=self.precision,
             backend=d)
 
+    @spans.spanned(spans.CODER)
     def pop(self, stack: ans.ANSStack):
         d = dispatch.resolve("pop_many_dyn", lanes=stack.lanes,
                              table_size=self.tables.shape[-1] - 1)
@@ -457,11 +462,13 @@ class _FusedBBANS(Codec):
         self._pop = jax.jit(pop_body, donate_argnums=dn,
                             static_argnames=("backend",))
 
+    @spans.spanned(spans.CODER)
     def push(self, stack: ans.ANSStack, s: Any) -> ans.ANSStack:
         return self._push(stack, s,
                           backend=dispatch.resolve("push_many",
                                                    lanes=stack.lanes))
 
+    @spans.spanned(spans.CODER)
     def pop(self, stack: ans.ANSStack):
         return self._pop(stack,
                          backend=dispatch.resolve("pop_many_grid",
@@ -508,11 +515,13 @@ class _FusedBitSwap(Codec):
         self._pop = jax.jit(pop_body, donate_argnums=dn,
                             static_argnames=("backend",))
 
+    @spans.spanned(spans.CODER)
     def push(self, stack: ans.ANSStack, s: Any) -> ans.ANSStack:
         return self._push(stack, s,
                           backend=dispatch.resolve("push_many",
                                                    lanes=stack.lanes))
 
+    @spans.spanned(spans.CODER)
     def pop(self, stack: ans.ANSStack):
         return self._pop(stack,
                          backend=dispatch.resolve("pop_many_grid",
@@ -554,6 +563,7 @@ class _FusedChained(Codec):
         self._pop = jax.jit(pop_body, donate_argnums=dn,
                             static_argnames=("backend",))
 
+    @spans.spanned(spans.CODER)
     def push(self, stack: ans.ANSStack, data: Any) -> ans.ANSStack:
         for leaf in jax.tree_util.tree_leaves(data):
             if leaf.shape[0] != self.n:
@@ -565,6 +575,7 @@ class _FusedChained(Codec):
                           backend=dispatch.resolve("push_many",
                                                    lanes=stack.lanes))
 
+    @spans.spanned(spans.CODER)
     def pop(self, stack: ans.ANSStack):
         return self._pop(stack,
                          backend=dispatch.resolve("pop_many_grid",
@@ -671,8 +682,10 @@ def _probe_params(rep: C.Repeat, leaf0, fields, statics):
                         _statics(lf, statics) != _statics(leaf0, statics):
                     out = None
                     break
-                if not all(bool(jnp.array_equal(arr[d], getattr(lf, nm)))
-                           for nm, arr in zip(fields, out)):
+                same = (bool(spans.host_read(
+                    jnp.array_equal(arr[d], getattr(lf, nm)),
+                    "compile.probe")) for nm, arr in zip(fields, out))
+                if not all(same):
                     out = None
                     break
             if out is not None:
@@ -693,14 +706,16 @@ def _validate_tables(tables: jnp.ndarray, precision: int,
     span, monotone starts, no zero-mass symbol. Runs once per lowering
     (the tables are already concrete), so a broken table fails here
     naming the subtree instead of as a hex mismatch at decode time."""
-    t = np.asarray(tables).astype(np.int64)
+    t = spans.host_read(tables, "compile.tables").astype(np.int64)
     total = 1 << precision
-    if (t[..., 0] != 0).any() or (t[..., -1] != total).any():
+    bad = (t[..., 0] != 0) | (t[..., -1] != total)
+    if bad.any():
+        first = tuple(int(i) for i in np.argwhere(bad)[0])
         raise ValueError(
             f"codecs.compile: contract violation (freq-sum) in {what}: "
-            f"table spans [{int(t[..., 0].min())}, "
-            f"{int(t[..., -1].max())}] instead of exactly "
-            f"[0, 2^{precision}]")
+            f"{int(bad.sum())} of {bad.size} tables do not span exactly "
+            f"[0, 2^{precision}]; the first, at {first}, spans "
+            f"[{int(t[first][0])}, {int(t[first][-1])}]")
     d = np.diff(t, axis=-1)
     if (d < 0).any():
         raise ValueError(
@@ -715,7 +730,7 @@ def _validate_tables(tables: jnp.ndarray, precision: int,
 
 def _validate_grid_params(arr: jnp.ndarray, name: str, what: str,
                           positive: bool = False) -> None:
-    a = np.asarray(arr)
+    a = spans.host_read(arr, "compile.grid_params")
     if not np.isfinite(a).all():
         raise ValueError(
             f"codecs.compile: contract violation (starts-monotone) in "
@@ -727,6 +742,7 @@ def _validate_grid_params(arr: jnp.ndarray, name: str, what: str,
             "scale flips the CDF and breaks the decode bisection)")
 
 
+@spans.spanned(spans.LOWER)
 def _lower_repeat(rep: C.Repeat, donate: bool) -> Optional[Codec]:
     """Probe a ``Repeat``'s positions; fuse when the leaf family allows.
 

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import ans
 from repro.core.codec import Codec
 
@@ -231,8 +232,8 @@ def unpack_lane_rows(buf: bytes, offset: int,
 
 def _pack(stack: ans.ANSStack, precision: int) -> bytes:
     msg, lengths = ans.flatten(stack)
-    msg_np = np.asarray(msg)
-    lengths_np = np.asarray(lengths)
+    msg_np = spans.host_read(msg, "container.msg")
+    lengths_np = spans.host_read(lengths, "container.lengths")
     lanes = msg_np.shape[0]
     return b"".join([
         _HEADER.pack(_MAGIC, _VERSION, precision, 0, lanes),

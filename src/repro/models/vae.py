@@ -23,7 +23,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import codecs
+from repro import codecs, spans
 from repro.codecs import quantize
 from repro.core import ans, discretize
 from repro.core.distributions import Bernoulli, BetaBinomial
@@ -245,6 +245,7 @@ def make_bb_codec(params: Params, cfg: VAEConfig, *,
         blob = codecs.compress(codecs.Chained(make_bb_codec(p, cfg), n),
                                data, lanes=lanes, seed=0)
     """
+    @spans.spanned(spans.FORWARD)
     def posterior(s):
         mu, sigma = encode(params, cfg, s)
         return codecs.Repeat(
@@ -252,6 +253,7 @@ def make_bb_codec(params: Params, cfg: VAEConfig, *,
                 mu[:, d], sigma[:, d], cfg.lat_bits, cfg.precision),
             cfg.latent)
 
+    @spans.spanned(spans.FORWARD)
     def likelihood(idx):
         y = discretize.bucket_centre(idx, cfg.lat_bits)
         obs_params = decode(params, cfg, y)

@@ -46,7 +46,7 @@ from typing import Any, Iterable, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro import stream
+from repro import spans, stream
 from repro.core import ans
 from repro.core.codec import Codec
 from repro.kernels import dispatch
@@ -210,10 +210,11 @@ def compress_dataset(codec: Codec, data: Any, *, n_shards: int,
                 segments[s].extend(encoders[s].write(placed))
         for s, enc in enumerate(encoders):
             segments[s].extend(enc.flush())
-    blob = fmt.encode_corpus(
-        [bytes(seg) for seg in segments],
-        [enc.n_symbols for enc in encoders],
-        lanes_per_shard=encoders[0].lanes, precision=precision)
+    with jax.profiler.TraceAnnotation(spans.FRAME):
+        blob = fmt.encode_corpus(
+            [bytes(seg) for seg in segments],
+            [enc.n_symbols for enc in encoders],
+            lanes_per_shard=encoders[0].lanes, precision=precision)
     if not with_info:
         return blob
     return blob, {"net_bits": sum(enc.net_bits for enc in encoders),
@@ -248,7 +249,8 @@ def decompress_dataset(codec: Codec, blob: bytes, *,
         xs = decompress_dataset(codec, compress_dataset(
             codec, xs, n_shards=4))
     """
-    header, entries = fmt.scan_corpus(blob)
+    with jax.profiler.TraceAnnotation(spans.FRAME):
+        header, entries = fmt.scan_corpus(blob)
     devs = list(devices) if devices is not None \
         else shard_devices(header.n_shards)
     outs = []

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import ans
 from repro.core.codec import Codec
 from repro.core.distributions import Categorical
@@ -179,6 +180,14 @@ register_lowering(BlockChain,
                   lambda c, rec: BlockChain(rec(c.inner), c.k))
 
 
+def _overflows(stack: ans.ANSStack) -> Tuple[int, int]:
+    """The stack's summed (overflows, underflows), read to the host."""
+    return (int(spans.host_read(jnp.sum(stack.overflows),
+                                "stream.overflows")),
+            int(spans.host_read(jnp.sum(stack.underflows),
+                                "stream.underflows")))
+
+
 def _resolve_block_codec(codec: Optional[Codec],
                          block_codec_fn: Optional[BlockCodecFn],
                          use_kernel: bool,
@@ -307,8 +316,9 @@ class StreamEncoder:
         if self._buffer:
             block, self._buffer = self._buffer, []
             out.append(self._encode_block(block))
-        out.append(fmt.encode_trailer(
-            fmt.Trailer(self.n_blocks, self.n_symbols)))
+        with jax.profiler.TraceAnnotation(spans.FRAME):
+            out.append(fmt.encode_trailer(
+                fmt.Trailer(self.n_blocks, self.n_symbols)))
         self._finished = True
         return self._emit(b"".join(out))
 
@@ -369,7 +379,8 @@ class StreamEncoder:
                 f"stream: snapshot mid-block ({len(self._buffer)} "
                 "datapoints buffered) - write a multiple of "
                 "block_symbols, or flush instead")
-        heads = (tuple(int(h) for h in np.asarray(self._heads))
+        heads = (tuple(int(h) for h in
+                       spans.host_read(self._heads, "stream.heads"))
                  if self._heads is not None else None)
         return EncoderSnapshot(
             lanes=self.lanes, block_symbols=self.block_symbols,
@@ -415,9 +426,10 @@ class StreamEncoder:
         if self._started:
             return b""
         self._started = True
-        return fmt.encode_header(fmt.StreamHeader(
-            lanes=self.lanes, block_symbols=self.block_symbols,
-            precision=self.precision))
+        with jax.profiler.TraceAnnotation(spans.FRAME):
+            return fmt.encode_header(fmt.StreamHeader(
+                lanes=self.lanes, block_symbols=self.block_symbols,
+                precision=self.precision))
 
     def _default_capacity(self, block: List[Any]) -> int:
         per_lane = sum(
@@ -478,22 +490,25 @@ class StreamEncoder:
 
     def _commit(self, stack: ans.ANSStack, bits_before: jnp.ndarray,
                 k: int, cap: int, chunks: int) -> bytes:
-        self.net_bits += float(ans.stack_content_bits(stack)) \
-            - float(bits_before)
+        self.net_bits += float(spans.host_read(
+            ans.stack_content_bits(stack), "stream.bits_after")) \
+            - float(spans.host_read(bits_before, "stream.bits_before"))
         self._heads = stack.head   # carry clean bits forward
         self._capacity, self._init_chunks = cap, chunks
-        msg, lengths = ans.flatten(stack)
         self.n_blocks += 1
         self.n_symbols += k
-        return fmt.encode_block(k, np.asarray(msg), np.asarray(lengths))
+        with jax.profiler.TraceAnnotation(spans.FRAME):
+            msg, lengths = ans.flatten(stack)
+            return fmt.encode_block(
+                k, spans.host_read(msg, "stream.msg"),
+                spans.host_read(lengths, "stream.lengths"))
 
     def _encode_sync(self, xs: Any, k: int, cap: int, chunks: int,
                      retries: int) -> bytes:
         for _ in range(retries):
             stack, bits_before = self._push_once(
                 xs, k, cap, chunks, self._heads, self.n_blocks)
-            over = int(jnp.sum(stack.overflows))
-            under = int(jnp.sum(stack.underflows))
+            over, under = _overflows(stack)
             if not over and not under:
                 return self._commit(stack, bits_before, k, cap, chunks)
             cap, chunks = self._grow(over, under, cap, chunks)
@@ -522,8 +537,7 @@ class StreamEncoder:
         if pend is None:
             raise RuntimeError("stream: no block in flight to finalize")
         self._pending = None
-        over = int(jnp.sum(pend.stack.overflows))
-        under = int(jnp.sum(pend.stack.underflows))
+        over, under = _overflows(pend.stack)
         if not over and not under:
             return self._commit(pend.stack, pend.bits_before, pend.k,
                                 pend.cap, pend.chunks), False
@@ -615,13 +629,16 @@ class StreamDecoder:
         self._buf.extend(chunk)
         out: List[Any] = []
         if self._header is None:
-            parsed = fmt.decode_header(bytes(self._buf))
+            with jax.profiler.TraceAnnotation(spans.FRAME):
+                parsed = fmt.decode_header(bytes(self._buf))
             if parsed is None:
                 return out
             self._header, off = parsed
             del self._buf[:off]
         while not self._finished:
-            res = fmt.decode_next(bytes(self._buf), 0, self._header.lanes)
+            with jax.profiler.TraceAnnotation(spans.FRAME):
+                res = fmt.decode_next(bytes(self._buf), 0,
+                                      self._header.lanes)
             if res is None:
                 break
             frame, off = res
@@ -644,13 +661,13 @@ class StreamDecoder:
     def _decode_block(self, block: fmt.Block) -> Any:
         # Width-2 rows mean a chunk-less block; keep a few buffer slots
         # so bits-back decode transients (posterior re-pushes) fit.
-        stack = ans.unflatten(jnp.asarray(block.msg),
-                              jnp.asarray(block.lengths),
-                              capacity=max(block.msg.shape[1] - 2, 8))
+        with jax.profiler.TraceAnnotation(spans.FRAME):
+            stack = ans.unflatten(jnp.asarray(block.msg),
+                                  jnp.asarray(block.lengths),
+                                  capacity=max(block.msg.shape[1] - 2, 8))
         codec = self._block_codec_fn(block.n_symbols)
         stack, xs = codec.pop(stack)
-        under = int(jnp.sum(stack.underflows))
-        over = int(jnp.sum(stack.overflows))
+        over, under = _overflows(stack)
         if under or over:
             raise ValueError(
                 f"stream: corrupt block {self.n_blocks} "
