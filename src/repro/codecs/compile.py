@@ -55,6 +55,7 @@ Import note: ``codecs.compile`` (the function re-exported by
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from typing import Any, Callable, Dict, Optional, Type
 
@@ -700,13 +701,48 @@ def _probe_params(rep: C.Repeat, leaf0, fields, statics):
             for nm in fields]
 
 
+#: the faults ``_table_faults`` flags, in the order of its verdict
+_TABLE_FAULTS = ("freq-sum", "starts-monotone", "freq-zero")
+
+
+@functools.partial(jax.jit, static_argnames=("precision",))
+def _table_faults(tables: jnp.ndarray, *, precision: int) -> jnp.ndarray:
+    """bool[3] verdict over every table: an end off [0, 2^precision],
+    a decreasing start, a zero-width symbol (``_TABLE_FAULTS``).
+    Comparisons only, in the tables' own dtype: exact at any precision,
+    and on a lane-sharded table one replicated verdict."""
+    lo, hi = tables[..., :-1], tables[..., 1:]
+    return jnp.stack([
+        jnp.any((tables[..., 0] != 0) | (tables[..., -1] != 1 << precision)),
+        jnp.any(hi < lo),
+        jnp.any(hi <= lo)])
+
+
 def _validate_tables(tables: jnp.ndarray, precision: int,
                      what: str) -> None:
     """Frequency-soundness gate on lowered fixed-point tables: exact
     span, monotone starts, no zero-mass symbol. Runs once per lowering
     (the tables are already concrete), so a broken table fails here
-    naming the subtree instead of as a hex mismatch at decode time."""
-    t = spans.host_read(tables, "compile.tables").astype(np.int64)
+    naming the subtree instead of as a hex mismatch at decode time.
+
+    The device checks the tables and only its 3-byte verdict is read;
+    a flagged table is then read whole, and ``_check_tables_host``
+    names the fault."""
+    flags = spans.host_read(_table_faults(tables, precision=precision),
+                            "compile.tables")
+    if flags.any():
+        _check_tables_host(spans.host_read(tables, "compile.tables"),
+                           precision, what)
+        raise ValueError(
+            f"codecs.compile: contract violation "
+            f"({_TABLE_FAULTS[int(np.argmax(flags))]}) in {what}")
+
+
+def _check_tables_host(tables: np.ndarray, precision: int,
+                       what: str) -> None:
+    """The host's check of tables read to it: raises on the first
+    fault, with how many tables break the span and the first one."""
+    t = tables.astype(np.int64)
     total = 1 << precision
     bad = (t[..., 0] != 0) | (t[..., -1] != total)
     if bad.any():
